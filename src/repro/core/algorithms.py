@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from repro.core import attacks as A
 from repro.core import aggregators as G
 from repro.core import compression as C
+from repro.core import stages
 from repro.core import wire as W
 
 
@@ -328,6 +329,7 @@ def init_state(cfg: AlgorithmConfig, d: int) -> ServerState:
 # --------------------------------------------------------------------------
 
 
+@stages.scope("attack")
 def _byzantine_overwrite(cfg: AlgorithmConfig, atk_state: Optional[Any],
                          wire: jnp.ndarray, key: jax.Array,
                          attack_params: Optional[jnp.ndarray] = None,
@@ -416,8 +418,9 @@ def _compressed_wire(cfg, atk_state, grads, mask_key, atk_key,
     # compressed_estimate dispatches between the jnp sparsifier (identical
     # make_masks + compress graph) and the repro.kernels.randk Block-RandK
     # round trip per SparsifierConfig.use_pallas
-    g_tilde = C.compressed_estimate(grads, mask_key, cfg.sparsifier,
-                                    ratio=ratio)
+    with stages.scope("sparsify"):
+        g_tilde = C.compressed_estimate(grads, mask_key, cfg.sparsifier,
+                                        ratio=ratio)
     return _byzantine_overwrite(cfg, atk_state, g_tilde, atk_key,
                                 attack_params, attack_idx)
 
@@ -431,6 +434,7 @@ def _row_mask(wire, prev, present, discount):
     return jnp.where(present[:, None], eff, prev)
 
 
+@stages.scope("aggregate")
 def _rosdhb_apply(cfg, agg, state, wire, hparams,
                   present=None, discount=None):
     # Step 5: per-worker server momentum (math dtype configurable — bf16
@@ -453,6 +457,7 @@ def _rosdhb_apply(cfg, agg, state, wire, hparams,
     return r, new
 
 
+@stages.scope("aggregate")
 def _dgd_apply(cfg, agg, state, wire, present=None, discount=None):
     # Compressed DGD, non-robust: plain mean of unbiased estimates (the
     # defining non-robust corner — the aggregator config is ignored).
@@ -469,6 +474,7 @@ def _dgd_apply(cfg, agg, state, wire, present=None, discount=None):
         step=state.step + 1)
 
 
+@stages.scope("aggregate")
 def _robust_dgd_apply(cfg, agg, state, wire, present=None, discount=None):
     # Robust DGD without compression: aggregate raw gradients (the
     # sparsifier config is ignored).
@@ -532,23 +538,29 @@ def _dasha_step(cfg, agg, state, grads, mask_key, atk_key, hparams,
     # k values + coordinate indices.
     sp = dataclasses.replace(cfg.sparsifier, local=True)
     one_m_a = hparams[3]
-    first = state.step == 0
-    m_prev = state.momentum.astype(jnp.float32)
-    h_prev = state.mirror.astype(jnp.float32)
-    g32 = grads.astype(jnp.float32)
-    m = jnp.where(first, g32, g32 + one_m_a * (m_prev - state.prev_grad))
-    masks = C.make_masks(mask_key, n, d, sp, dtype=grads.dtype, ratio=ratio)
-    alpha = (1.0 / ratio) if ratio is not None else sp.alpha
-    b = 1.0 / (2.0 * alpha)
-    diff = C.compress((m - m_prev) + b * (m_prev - h_prev), masks, sp,
-                      ratio=ratio)
-    h = h_prev + diff
+    # the wire half (MVR momentum, compressed difference, mirror) is the
+    # round's sparsify stage; the server's aggregate of the mirrors its
+    # aggregate stage
+    with stages.scope("sparsify"):
+        first = state.step == 0
+        m_prev = state.momentum.astype(jnp.float32)
+        h_prev = state.mirror.astype(jnp.float32)
+        g32 = grads.astype(jnp.float32)
+        m = jnp.where(first, g32, g32 + one_m_a * (m_prev - state.prev_grad))
+        masks = C.make_masks(mask_key, n, d, sp, dtype=grads.dtype,
+                             ratio=ratio)
+        alpha = (1.0 / ratio) if ratio is not None else sp.alpha
+        b = 1.0 / (2.0 * alpha)
+        diff = C.compress((m - m_prev) + b * (m_prev - h_prev), masks, sp,
+                          ratio=ratio)
+        h = h_prev + diff
     h, atk = _byzantine_overwrite(cfg, state.attack, h, atk_key,
                                   attack_params, attack_idx)
-    r = agg(h)
-    mdt = jnp.dtype(cfg.momentum_dtype)
-    new = ServerState(momentum=m.astype(mdt), mirror=h.astype(mdt),
-                      prev_grad=g32, step=state.step + 1, attack=atk)
+    with stages.scope("aggregate"):
+        r = agg(h)
+        mdt = jnp.dtype(cfg.momentum_dtype)
+        new = ServerState(momentum=m.astype(mdt), mirror=h.astype(mdt),
+                          prev_grad=g32, step=state.step + 1, attack=atk)
     return r, new
 
 
@@ -808,11 +820,16 @@ def server_round(cfg: AlgorithmConfig, state: ServerState,
     """
     n, d = grads.shape
     assert n == cfg.n_workers, (n, cfg.n_workers)
-    if cfg.clip_norm is not None:
-        norms = jnp.linalg.norm(grads.astype(jnp.float32), axis=1,
-                                keepdims=True)
-        scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(norms, 1e-12))
-        grads = (grads * scale.astype(grads.dtype))
+    # what the wire is made from: clipped rows and the round's mask and
+    # attack keys
+    with stages.scope("sparsify"):
+        if cfg.clip_norm is not None:
+            norms = jnp.linalg.norm(grads.astype(jnp.float32), axis=1,
+                                    keepdims=True)
+            scale = jnp.minimum(1.0,
+                                cfg.clip_norm / jnp.maximum(norms, 1e-12))
+            grads = (grads * scale.astype(grads.dtype))
+        mask_key, atk_key = jax.random.split(key)
     ratio = attack_idx = hparams = None
     if scenario is not None:
         if scenario.attack_coeffs is not None:
@@ -820,7 +837,6 @@ def server_round(cfg: AlgorithmConfig, state: ServerState,
         attack_idx = scenario.attack_idx
         ratio = scenario.ratio
         hparams = scenario.hparams
-    mask_key, atk_key = jax.random.split(key)
     if aggregator is not None:
         agg = aggregator
     elif scenario is not None and scenario.agg_idx is not None:

@@ -9,6 +9,11 @@ explicitly *batched* entry points over the grid engine's fused
 ``[n_cells * n_seeds]`` leading axis; :func:`batchable` routes ``jax.vmap``
 of the per-lane rule onto them.
 
+Each ``pallas_call`` is named after its kernel (``name=``): the compiled
+custom-call instruction, and so a profiler trace, carries that name
+(``compress``, ``decompress``, ``cwtm``, ``attention``, ...), whatever
+function or scope encloses the call.
+
 Every ``use_pallas`` flag of the package resolves the same way
 (:func:`use_kernel`, :func:`resolve_kernel_backend`): ``None`` means the
 compiled kernel on a TPU and the jnp oracle elsewhere; ``True`` forces the

@@ -97,13 +97,14 @@ def sorted_weight_kernel(x_ref, o_ref, *, n_pad: int,
 
 
 def sorted_weighted_batched(x: jnp.ndarray, weights: Sequence[float], *,
-                            block_d: int = 2048,
+                            name: str, block_d: int = 2048,
                             interpret: bool = False) -> jnp.ndarray:
     """Static rank weighting of the sorted worker axis: [B, n, d] -> [B, d].
 
     The shared tile plumbing of the CWTM / coordinate-wise-median kernels:
     grid (B, d/block_d), each step one memory-bound [n_pad, block_d] read.
-    ``weights[i]`` scales the i-th smallest value per coordinate.
+    ``weights[i]`` scales the i-th smallest value per coordinate; ``name``
+    names the kernel's call (the compiled instruction, as a trace shows it).
     """
     b, n, d = x.shape
     weights = tuple(float(w) for w in weights)
@@ -135,6 +136,7 @@ def sorted_weighted_batched(x: jnp.ndarray, weights: Sequence[float], *,
         out_specs=pl.BlockSpec((1, 1, block_d), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, 1, dp), x.dtype),
         interpret=interpret,
+        name=name,
     )(x)
     return out[:, 0, :d]
 
@@ -151,7 +153,8 @@ def cwtm_pallas_batched(x: jnp.ndarray, f: int, *, block_d: int = 2048,
     """Batched coordinate-wise trimmed mean: x [B, n, d] -> [B, d] — the
     grid engine's real shape (B = n_cells * n_seeds fusion lanes)."""
     return sorted_weighted_batched(x, cwtm_weights(x.shape[1], f),
-                                   block_d=block_d, interpret=interpret)
+                                   name="cwtm", block_d=block_d,
+                                   interpret=interpret)
 
 
 def cwtm_pallas(x: jnp.ndarray, f: int, *, block_d: int = 2048,

@@ -136,6 +136,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="attention",
         **kwargs,
     )(qt, kt, vt)
     out = jnp.moveaxis(out, 1, 2)[:, :sq]

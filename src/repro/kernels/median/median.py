@@ -36,7 +36,8 @@ def median_pallas_batched(x: jnp.ndarray, *, block_d: int = 2048,
     """Batched coordinate-wise median: x [B, n, d] -> [B, d] — the grid
     engine's real shape (B = n_cells * n_seeds fusion lanes)."""
     return sorted_weighted_batched(x, median_weights(x.shape[1]),
-                                   block_d=block_d, interpret=interpret)
+                                   name="median", block_d=block_d,
+                                   interpret=interpret)
 
 
 def median_pallas(x: jnp.ndarray, *, block_d: int = 2048,

@@ -78,6 +78,7 @@ def pairdist_pallas_batched(x: jnp.ndarray, *, block_d: int = 2048,
         out_specs=pl.BlockSpec((1, n_pad, n_pad), lambda i, j: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n_pad, n_pad), jnp.float32),
         interpret=interpret,
+        name="pairdist",
     )(x)
     return out[:, :n, :n]
 

@@ -57,10 +57,10 @@ def _segmented(make_call, prefetch: jnp.ndarray, operands, out_shape):
 
 
 def _call(kernel, base_specs, out_spec, out_shape, grid: int,
-          aliased: bool, interpret: bool):
-    """pallas_call with one scalar-prefetch operand; with ``aliased`` the
-    output buffer rides as an extra operand left in HBM (``pl.ANY``) and
-    aliased to the output."""
+          aliased: bool, interpret: bool, name: str):
+    """pallas_call ``name`` with one scalar-prefetch operand; with
+    ``aliased`` the output buffer rides as an extra operand left in HBM
+    (``pl.ANY``) and aliased to the output."""
     in_specs = list(base_specs)
     aliases = {}
     if aliased:
@@ -74,6 +74,7 @@ def _call(kernel, base_specs, out_spec, out_shape, grid: int,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
+        name=name,
     )
 
 
@@ -103,7 +104,7 @@ def block_compress(g: jnp.ndarray, block_idx: jnp.ndarray, block_size: int,
             functools.partial(_compress_kernel, alpha=alpha),
             [pl.BlockSpec(blk, lambda j, idx: (idx[j], 0, 0))],
             pl.BlockSpec(blk, lambda j, idx: (base + j, 0, 0)),
-            out_shape, size, aliased, interpret)
+            out_shape, size, aliased, interpret, "compress")
 
     out = _segmented(make_call, block_idx,
                      (g.reshape(nb, 1, block_size),), out_shape)
@@ -152,7 +153,7 @@ def block_decompress(payload: jnp.ndarray, block_idx: jnp.ndarray,
             [pl.BlockSpec(blk,
                           lambda i, sel: (jnp.maximum(sel[i], 0), 0, 0))],
             pl.BlockSpec(blk, lambda i, sel: (base + i, 0, 0)),
-            out_shape, size, aliased, interpret)
+            out_shape, size, aliased, interpret, "decompress")
 
     out = _segmented(make_call, _slot_map(block_idx, nb),
                      (payload.reshape(kb, 1, block_size),), out_shape)
@@ -196,7 +197,7 @@ def momentum_scatter(bank_row: jnp.ndarray, payload: jnp.ndarray,
              pl.BlockSpec(blk,
                           lambda i, sel: (jnp.maximum(sel[i], 0), 0, 0))],
             pl.BlockSpec(blk, lambda i, sel: (base + i, 0, 0)),
-            out_shape, size, aliased, interpret)
+            out_shape, size, aliased, interpret, "momentum_update")
 
     out = _segmented(make_call, _slot_map(block_idx, nb),
                      (bank_row.reshape(nb, 1, block_size),

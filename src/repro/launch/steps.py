@@ -36,6 +36,7 @@ from repro.core import algorithms as alg
 from repro.core import aggregators as agg_lib
 from repro.core import attacks as atk_lib
 from repro.core import compression as comp_lib
+from repro.core import stages
 from repro.kernels import resolve_kernel_backend
 from repro.models import transformer as tf
 from repro.models.config import ModelConfig
@@ -186,7 +187,10 @@ def build_train_step(plan: TrainPlan, mesh: Mesh):
 
     def train_step(state: TrainState, batch: Dict[str, jnp.ndarray]
                    ) -> Tuple[TrainState, Dict[str, jnp.ndarray]]:
-        key, round_key = jax.random.split(state.key)
+        # each stage runs in its named scope (repro.core.stages); the
+        # server round's stages are scoped where the round is written
+        with stages.scope("update"):
+            key, round_key = jax.random.split(state.key)
 
         # (1) per-worker gradients: batch leaves are [n_workers, local, ...].
         # spmd_axis_name pins the vmapped worker dim to the data-parallel
@@ -195,64 +199,69 @@ def build_train_step(plan: TrainPlan, mesh: Mesh):
         # the worker dim (§Perf iter 5: 283 GiB/chip of f32 residuals at
         # mistral-123B scale).
         dp = sp.dp_axes(mesh)
-        # mixed precision (§Perf iter 8): differentiate wrt a bf16 cast of
-        # the f32 master params — halves the per-worker gradient transient
-        # (the f32 master is only touched by the final update).
-        half = jax.tree_util.tree_map(
-            lambda p: p.astype(jnp.bfloat16)
-            if p.dtype == jnp.float32 else p, state.params)
-        losses, grads = jax.vmap(
-            jax.value_and_grad(loss_fn), in_axes=(None, 0),
-            spmd_axis_name=dp if len(dp) > 1 else dp[0])(
-                half, batch)
+        with stages.scope("grads"):
+            # mixed precision (§Perf iter 8): differentiate wrt a bf16 cast
+            # of the f32 master params — halves the per-worker gradient
+            # transient (the f32 master is only touched by the final
+            # update).
+            half = jax.tree_util.tree_map(
+                lambda p: p.astype(jnp.bfloat16)
+                if p.dtype == jnp.float32 else p, state.params)
+            losses, grads = jax.vmap(
+                jax.value_and_grad(loss_fn), in_axes=(None, 0),
+                spmd_axis_name=dp if len(dp) > 1 else dp[0])(
+                    half, batch)
 
-        # The barrier keeps the TPU compiler from fusing the gradient
-        # producers (the LM head's dot, the embedding's scatter-add) into
-        # the flatten: fused, a published-width step at a 50k vocabulary
-        # took it over ten minutes and ~15 GB of host memory to compile.
-        grads = jax.lax.optimization_barrier(grads)
+            # The barrier keeps the TPU compiler from fusing the gradient
+            # producers (the LM head's dot, the embedding's scatter-add)
+            # into the flatten: fused, a published-width step at a 50k
+            # vocabulary took it over ten minutes and ~15 GB of host
+            # memory to compile.
+            grads = jax.lax.optimization_barrier(grads)
 
         # (2) flatten to the coordinate-sharded virtual-server layout.
         # 'sharded': transpose-major flatten keeps GSPMD shardings intact in
         # the producer layout [n(data), D(model)]; the reshard to the bank
         # layout [n, D(all axes)] below is the algorithm's one all-to-all
         # ("workers send their k coordinates to the server").
-        if plan.flatten == "sharded":
-            # hand-scheduled per-leaf all-to-all into the interleaved bank
-            # layout (§Perf iter 4c) — the only formulation GSPMD partitions
-            gflat = sf.flatten_to_bank(grads, fspec, mesh, dtype=wire_dtype)
-        else:
-            gflat = T.stacked_ravel(grads, fspec, dtype=wire_dtype)
-            gflat = jax.lax.with_sharding_constraint(
-                gflat, NamedSharding(mesh, bank_sharding))
+        with stages.scope("flatten"):
+            if plan.flatten == "sharded":
+                # hand-scheduled per-leaf all-to-all into the interleaved
+                # bank layout (§Perf iter 4c) — the only formulation GSPMD
+                # partitions
+                gflat = sf.flatten_to_bank(grads, fspec, mesh,
+                                           dtype=wire_dtype)
+            else:
+                gflat = T.stacked_ravel(grads, fspec, dtype=wire_dtype)
+                gflat = jax.lax.with_sharding_constraint(
+                    gflat, NamedSharding(mesh, bank_sharding))
 
         # (3) paper steps 1-6 on the [n, D] bank
-        direction, server, aux = alg.server_round(
+        direction, server, _ = alg.server_round(
             algo, state.server, gflat, round_key, aggregator=aggregator)
 
         # (4) step 7: unflatten + SGD update of the master params
-        if plan.flatten == "sharded":
-            dir_tree = sf.bank_to_param_tree(direction, fspec, mesh)
-        else:
-            dir_tree = T.tree_unravel(direction, fspec)
-        new_params = jax.tree_util.tree_map(
-            lambda p, d: (p - algo.gamma * d.astype(p.dtype)),
-            state.params, dir_tree)
-
-        metrics = {
-            "loss": jnp.mean(losses[algo.f:]),
-            "dir_norm": jnp.linalg.norm(direction),
-            "payload_floats_per_worker": jnp.asarray(
-                aux["payload_floats_per_worker"], jnp.float32),
-        }
-        return TrainState(new_params, server, state.step + 1, key), metrics
+        with stages.scope("update"):
+            if plan.flatten == "sharded":
+                dir_tree = sf.bank_to_param_tree(direction, fspec, mesh)
+            else:
+                dir_tree = T.tree_unravel(direction, fspec)
+            new_params = jax.tree_util.tree_map(
+                lambda p, d: (p - algo.gamma * d.astype(p.dtype)),
+                state.params, dir_tree)
+            metrics = {
+                "loss": jnp.mean(losses[algo.f:]),
+                "dir_norm": jnp.linalg.norm(direction),
+            }
+            step = state.step + 1
+        return TrainState(new_params, server, step, key), metrics
 
     return train_step
 
 
 def build_chunked_train_step(plan: TrainPlan, mesh: Mesh,
                              chunk_size: int):
-    """Scan ``chunk_size`` rounds of :func:`build_train_step` inside ONE
+    """Loop ``chunk_size`` rounds of :func:`build_train_step` inside ONE
     compiled program: ``chunk_step(state, chunk) -> (state, metrics)`` with
     ``chunk`` leaves ``[chunk_size, n_workers, ...]`` and metrics stacked
     ``[chunk_size]``.
@@ -260,7 +269,7 @@ def build_chunked_train_step(plan: TrainPlan, mesh: Mesh,
     This is the device program the streaming launch driver dispatches once
     per ring-buffer chunk (``repro.data.stream.ChunkPrefetcher``): host
     dispatch and batch residency drop from O(steps) to O(chunk), and the
-    scan carry is exactly the ``TrainState`` of :func:`train_input_specs` —
+    loop carries exactly the ``TrainState`` of :func:`train_input_specs` —
     mirror/prev_grad slots pruned by the algorithm's resolved
     ``StateLayout``, so chunking never widens the carry.
     """
@@ -270,7 +279,33 @@ def build_chunked_train_step(plan: TrainPlan, mesh: Mesh,
 
     def chunk_step(state: TrainState, chunk: Dict[str, jnp.ndarray]
                    ) -> Tuple[TrainState, Dict[str, jnp.ndarray]]:
-        return jax.lax.scan(step, state, chunk)
+        # a scan written out, so that the loop's own work (the round's
+        # batch, the outputs' rows, the counter) runs in stage scopes too
+        def cond(carry):
+            with stages.scope("update"):
+                return carry[0] < chunk_size
+
+        def body(carry):
+            t, state, out = carry
+            with stages.scope("grads"):
+                batch = jax.tree_util.tree_map(
+                    lambda x: jax.lax.dynamic_index_in_dim(
+                        x, t, keepdims=False), chunk)
+            state, metrics = step(state, batch)
+            with stages.scope("update"):
+                out = jax.tree_util.tree_map(
+                    lambda o, m: jax.lax.dynamic_update_index_in_dim(
+                        o, m, t, 0), out, metrics)
+                return t + 1, state, out
+
+        with stages.scope("update"):
+            first = jax.tree_util.tree_map(lambda x: x[0], chunk)
+            out = jax.tree_util.tree_map(
+                lambda m: jnp.zeros((chunk_size,) + m.shape, m.dtype),
+                jax.eval_shape(step, state, first)[1])
+            start = jnp.zeros((), jnp.int32)
+        _, state, out = jax.lax.while_loop(cond, body, (start, state, out))
+        return state, out
 
     return chunk_step
 

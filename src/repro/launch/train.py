@@ -29,6 +29,7 @@ from repro.configs.base import ArchSpec, InputShape
 from repro.core import AggregatorConfig, AttackConfig, SparsifierConfig
 from repro.core import aggregators as agg_lib
 from repro.core import algorithms as alg
+from repro.core import stages
 from repro.data import ChunkPrefetcher
 from repro.kernels import backend_label, resolve_kernel_backend
 from repro.launch.mesh import make_host_mesh
@@ -95,7 +96,9 @@ def compile_train_step(plan: TrainPlan, mesh: Mesh,
     ``mesh`` — with ``chunk_size``, the step scanned over that many rounds
     (:func:`build_chunked_train_step`). The state is donated: the ``[n, D]``
     bank and the master params are the largest buffers, and without
-    donation each would exist twice at the step's peak."""
+    donation each would exist twice at the step's peak. The executable is
+    kept as the step compiled last (``repro.core.stages.record``), whose
+    stage table is built only when read."""
     state_specs, batch_specs = train_input_specs(plan, mesh)
     step = build_train_step(plan, mesh)
     if chunk_size is not None:
@@ -107,9 +110,11 @@ def compile_train_step(plan: TrainPlan, mesh: Mesh,
         lambda s: s.sharding or NamedSharding(mesh, P()), state_specs,
         is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
     with mesh:
-        return jax.jit(step, donate_argnums=0,
-                       out_shardings=(state_sh, None)).lower(
+        compiled = jax.jit(step, donate_argnums=0,
+                           out_shardings=(state_sh, None)).lower(
             state_specs, batch_specs).compile()
+    stages.record(compiled)
+    return compiled
 
 
 def init_train_state(plan: TrainPlan, compiled, seed: int) -> TrainState:

@@ -151,3 +151,46 @@ def test_bank_exchange_compiles_for_four_chips(topo, no_persistent_cache,
         hlo = jax.jit(round_).lower(grads).compile().as_text()
     assert "bf16[4,8," in hlo.split("all-to-all(")[0].rsplit("\n", 1)[-1]
     assert "tpu_custom_call" in hlo
+
+
+def test_train_step_kernels_keep_their_names_and_stages(
+        topo, no_persistent_cache, monkeypatch):
+    """The trainer's step at a small width: the four kernels the benchmark
+    reads by name are custom-calls under those names, each in its stage
+    of the program's table."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+
+    import repro.kernels
+    from repro.configs import get_arch
+    from repro.configs.base import InputShape
+    from repro.core import (AggregatorConfig, AttackConfig, SparsifierConfig,
+                            stages)
+    from repro.launch.steps import make_train_plan
+    from repro.launch.train import compile_train_step
+
+    # the kernel dispatch takes its TPU branch, as on the chip
+    monkeypatch.setattr(repro.kernels, "on_tpu", lambda: True)
+    spec = get_arch("stablelm_3b")
+    spec = dataclasses.replace(spec, model=spec.model.reduced(
+        n_layers=1, d_model=256).with_overrides(use_flash_attention=None))
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    plan = make_train_plan(
+        spec, InputShape("train", 256, 4, "train"), mesh,
+        {"f": 1, "momentum_dtype": "bfloat16",
+         "server_compute_dtype": "bfloat16",
+         "sparsifier": SparsifierConfig(kind="block", ratio=0.05,
+                                        block_size=512),
+         "aggregator": AggregatorConfig(name="cwtm", f=1),
+         "attack": AttackConfig(name="alie")}, n_workers=4)
+    compile_train_step(plan, mesh)
+    kernels = {}
+    for name, op in stages.last_table().items():
+        if op.kernel:
+            kernels.setdefault(name.rsplit(".", 1)[0], set()).add(op.stage)
+    want = {"attention": {"grads"}, "compress": {"sparsify"},
+            "decompress": {"sparsify"}, "cwtm": {"aggregate"}}
+    assert {k: kernels.get(k) for k in want} == want, kernels
