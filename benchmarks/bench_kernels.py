@@ -113,7 +113,7 @@ def _legacy_micro(results):
     (randk compressor + flash-attention reference paths)."""
     key = jax.random.PRNGKey(0)
     d, bs = 1 << 20, 512
-    g = jax.random.normal(key, (d,))
+    g = jax.random.normal(key, (1, d))
     idx = jnp.arange(0, d // bs, 16, dtype=jnp.int32)
     us = time_fn(jax.jit(lambda a: block_compress_ref(a, idx, bs, 16.0)), g,
                  iters=5)

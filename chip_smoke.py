@@ -257,23 +257,28 @@ def phase_kernels(*, d: int = 1 << 20, n: int = 13, f: int = 3,
 
     nb = d // block
     kb = max(1, round(ratio * nb))
-    ks = jax.random.split(key, 4)
-    g = jax.random.normal(ks[0], (d,), jnp.float32)
+    ks = jax.random.split(key, 5)
+    bank = jax.random.normal(ks[0], (4, d), jnp.float32)
     m = jax.random.normal(ks[1], (d,), jnp.float32)
-    ids = jax.random.permutation(ks[2], nb)[:kb].astype(jnp.int32)
+    ids = jnp.sort(jax.random.permutation(ks[2], nb)[:kb]).astype(jnp.int32)
+    local = jnp.sort(jax.vmap(lambda kk: jax.random.permutation(kk, nb)[:kb])(
+        jax.random.split(ks[4], 4)), axis=-1).astype(jnp.int32)
     alpha = 1.0 / ratio
-    payload = RR.block_compress_ref(g, ids, block, alpha)
-    check("randk compress", lambda a, i: RK.block_compress(a, i, block, alpha),
-          lambda a, i: RR.block_compress_ref(a, i, block, alpha),
-          (g, ids), F32_REL)
-    check("randk decompress",
-          lambda p, i: RK.block_decompress(p, i, block, d),
-          lambda p, i: RR.block_decompress_ref(p, i, block, d),
-          (payload, ids), F32_REL)
+    for mask, i in (("global", ids), ("local", local)):
+        payload = RR.block_compress_ref(bank, i, block, alpha)
+        check(f"randk compress {mask}",
+              lambda a, i: RK.block_compress(a, i, block, alpha),
+              lambda a, i: RR.block_compress_ref(a, i, block, alpha),
+              (bank, i), F32_REL)
+        check(f"randk decompress {mask}",
+              lambda p, i: RK.block_decompress(p, i, block, d),
+              lambda p, i: RR.block_decompress_ref(p, i, block, d),
+              (payload, i), F32_REL)
     check("randk scatter",
           lambda r, p, i: RK.momentum_scatter(r, p, i, block, 0.9),
           lambda r, p, i: RR.momentum_scatter_ref(r, p, i, block, 0.9),
-          (m, payload, ids), F32_REL)
+          (m, RR.block_compress_ref(bank, ids, block, alpha)[0], ids),
+          F32_REL)
 
     q, k, v = (jax.random.normal(kk, (1, seq, heads, head_dim),
                                  jnp.float32).astype(jnp.bfloat16)

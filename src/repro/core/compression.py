@@ -255,7 +255,8 @@ def compressed_estimate(grads: jnp.ndarray, mask_key: jax.Array,
     with exactly the ``_block_mask`` permutation (same key, same
     ``round(ratio * nb)`` count — global masks share one id vector, local
     masks split the key per worker), and the scatter of ``alpha * g`` is
-    bitwise the f32 mask-multiply on finite gradients.
+    bitwise the f32 mask-multiply on finite gradients. Each kernel is one
+    call over the whole bank.
     """
     n, d = grads.shape
     kb = _kernel_backend(cfg, d, ratio)
@@ -276,18 +277,12 @@ def compressed_estimate(grads: jnp.ndarray, mask_key: jax.Array,
     if cfg.local:
         ids = jax.vmap(block_ids)(jax.random.split(mask_key, n))
     else:
-        ids = jnp.broadcast_to(block_ids(mask_key), (n, k_blocks))
-
-    def roundtrip(args):
-        g_row, id_row = args
-        payload = RK.compress(g_row, id_row, block_size=cfg.block_size,
-                              alpha=cfg.alpha, use_pallas=True,
-                              interpret=kb["interpret"])
-        return RK.decompress(payload, id_row, block_size=cfg.block_size,
-                             d=d, use_pallas=True,
-                             interpret=kb["interpret"])
-
-    return jax.lax.map(roundtrip, (grads, ids))
+        ids = block_ids(mask_key)
+    payload = RK.compress(grads, ids, block_size=cfg.block_size,
+                          alpha=cfg.alpha, use_pallas=True,
+                          interpret=kb["interpret"])
+    return RK.decompress(payload, ids, block_size=cfg.block_size, d=d,
+                         use_pallas=True, interpret=kb["interpret"])
 
 
 def payload_floats(d: int, cfg: SparsifierConfig) -> int:

@@ -11,6 +11,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core import compression as C
+from repro.kernels.randk import randk as K
 
 
 @pytest.mark.parametrize("kind", ["randk", "bernoulli", "block", "block_hash"])
@@ -266,6 +267,22 @@ def test_compressed_estimate_kernel_matches_jnp(local):
     # sanity: it actually compressed (3/4 of blocks zeroed)
     kept = float(jnp.mean(jnp.any(ref.reshape(n, -1, block) != 0, axis=-1)))
     assert kept <= 0.5
+
+
+@pytest.mark.parametrize("local", [False, True])
+def test_compressed_estimate_kernel_matches_jnp_ragged_tile(local):
+    """1,100 blocks a row, 1,024 a decompress tile: a ragged last tile of
+    76, and the round trip is still the mask-multiply."""
+    n, block = 4, 128
+    d = 1100 * block
+    assert K._blocks_per_step(n, block, jnp.float32) == 1024
+    cfg = C.SparsifierConfig(kind="block", ratio=0.1, block_size=block,
+                             local=local)
+    grads = jax.random.normal(jax.random.PRNGKey(4), (n, d))
+    key = jax.random.PRNGKey(8)
+    ref = C.compressed_estimate(grads, key, dataclasses.replace(cfg, use_pallas=False))
+    got = C.compressed_estimate(grads, key, dataclasses.replace(cfg, use_pallas=True))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
 def test_compressed_estimate_kernel_ineligible_falls_back():

@@ -106,18 +106,101 @@ def test_batched_matches_vmapped_2d():
                                      (8192, 512, 3)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_randk_roundtrip_sweep(d, bs, kb, dtype):
-    nb = d // bs
-    g = jax.random.normal(KEY, (d,)).astype(dtype)
+    n, nb = 4, d // bs
+    g = jax.random.normal(KEY, (n, d)).astype(dtype)
     idx = jnp.sort(jax.random.permutation(jax.random.PRNGKey(d), nb)[:kb])
     alpha = float(nb) / kb
     p = block_compress(g, idx, bs, alpha, interpret=True)
     p_ref = block_compress_ref(g, idx, bs, alpha)
-    np.testing.assert_allclose(np.asarray(p, np.float32),
-                               np.asarray(p_ref, np.float32), rtol=2e-2)
+    np.testing.assert_array_equal(np.asarray(p), np.asarray(p_ref))
     dn = block_decompress(p, idx, bs, d, interpret=True)
     dn_ref = block_decompress_ref(p_ref, idx, bs, d)
-    np.testing.assert_allclose(np.asarray(dn, np.float32),
-                               np.asarray(dn_ref, np.float32), rtol=2e-2)
+    np.testing.assert_array_equal(np.asarray(dn), np.asarray(dn_ref))
+
+
+def _kept_ids(pattern, nb, kb, tile, key):
+    """Sorted kept block ids: ``random`` a permutation prefix; ``runs``
+    whole tiles kept around tiles with none (kb a multiple of tile)."""
+    if pattern == "random":
+        return jnp.sort(jax.random.permutation(key, nb)[:kb])
+    first = int(jax.random.randint(key, (), 0, nb // tile - kb // tile))
+    return jnp.arange(first * tile, first * tile + kb)
+
+
+# (n, nb, kb, step, window, pattern): step is the blocks a grid step
+# covers, set through TILE_BYTES; nb % step != 0 leaves a ragged last
+# tile; "runs" gives tiles with no kept block and tiles all kept; an id
+# window as short as the tile splits some tiles' ids over two windows, and
+# several chunks share a longer one; window None keeps the TPU's own
+BANK_CASES = [
+    (4, 37, 11, 4, None, "random"),
+    (3, 64, 30, 8, 8, "random"),
+    (2, 41, 16, 8, 8, "runs"),
+    (4, 40, 24, 8, 16, "runs"),
+    (4, 40, 24, 4, 4, "random"),
+    (1, 19, 19, 16, None, "random"),
+    (5, 130, 40, 128, None, "random"),
+]
+
+
+@pytest.mark.parametrize("local", [False, True], ids=["global", "local"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("n,nb,kb,step,window,pattern", BANK_CASES)
+def test_randk_bank_round_trip(monkeypatch, n, nb, kb, step, window, pattern,
+                               dtype, local):
+    """compress and decompress over a whole [n, D] bank, for one shared id
+    vector or one per row, match the oracles bit for bit."""
+    from repro.kernels.randk import randk as K
+    if window is not None:
+        monkeypatch.setattr(K, "ID_WINDOW", window)
+    bs = 128
+    rows = max(K._dma_rows(n, dtype), 4)
+    monkeypatch.setattr(K, "TILE_BYTES",
+                        step * rows * bs * jnp.dtype(dtype).itemsize)
+    assert K._blocks_per_step(rows, bs, dtype) == step
+    d = nb * bs
+    g = jax.random.normal(jax.random.PRNGKey(n * nb + kb), (n, d)
+                          ).astype(dtype)
+    keys = jax.random.split(jax.random.PRNGKey(kb), n)
+    tile = min(step, nb)
+    idx = (jnp.stack([_kept_ids(pattern, nb, kb, tile, k) for k in keys])
+           if local else _kept_ids(pattern, nb, kb, tile, keys[0]))
+    idx = idx.astype(jnp.int32)
+    p = K.block_compress(g, idx, bs, 3.0, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(p), np.asarray(block_compress_ref(g, idx, bs, 3.0)))
+    dn = K.block_decompress(p, idx, bs, d, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(dn), np.asarray(block_decompress_ref(p, idx, bs, d)))
+
+
+@pytest.mark.parametrize("local", [False, True], ids=["global", "local"])
+def test_randk_ops_ids_are_a_set(local):
+    """ops.compress / ops.decompress take ids in any order: both backends
+    send the kept blocks in ascending order and rebuild the same bank."""
+    from repro.kernels.randk import ops as RK
+    n, bs, nb, kb = 4, 128, 24, 7
+    g = jax.random.normal(KEY, (n, nb * bs))
+    keys = jax.random.split(jax.random.PRNGKey(9), n)
+    perm = [jax.random.permutation(k, nb)[:kb] for k in keys]
+    idx = (jnp.stack(perm) if local else perm[0]).astype(jnp.int32)
+    out = {}
+    for pallas in (False, True):
+        p = RK.compress(g, idx, block_size=bs, alpha=2.0, use_pallas=pallas,
+                        interpret=pallas)
+        out[pallas] = (p, RK.decompress(p, idx, block_size=bs, d=nb * bs,
+                                        use_pallas=pallas, interpret=pallas))
+    np.testing.assert_array_equal(np.asarray(out[True][0]),
+                                  np.asarray(out[False][0]))
+    np.testing.assert_array_equal(np.asarray(out[True][1]),
+                                  np.asarray(out[False][1]))
+    kept = jnp.broadcast_to(jnp.zeros(nb, bool).at[idx].set(True)
+                            if not local else
+                            jax.vmap(lambda i: jnp.zeros(nb, bool).at[i]
+                                     .set(True))(idx), (n, nb))
+    want = jnp.where(jnp.repeat(kept, bs, axis=1), 2.0 * g, 0.0)
+    np.testing.assert_array_equal(np.asarray(out[True][1]), np.asarray(want))
 
 
 @pytest.mark.parametrize("beta", [0.0, 0.9, 0.99])
@@ -134,21 +217,17 @@ def test_momentum_scatter_sweep(beta):
 
 @pytest.mark.parametrize("max_prefetch", [3, 8])
 def test_randk_segmented_prefetch(monkeypatch, max_prefetch):
-    """Id and slot vectors longer than one call may prefetch run as a chain
-    of calls writing one aliased output; results match the oracles."""
+    """Slot vectors longer than one call may prefetch run the momentum
+    scatter as a chain of calls writing one aliased output; it matches
+    its oracle."""
     from repro.kernels.randk import randk as K
     monkeypatch.setattr(K, "MAX_PREFETCH", max_prefetch)
     d, bs, kb = 4096, 128, 10
     nb = d // bs
     g = jax.random.normal(KEY, (d,))
+    p = jax.random.normal(jax.random.PRNGKey(4), (kb * bs,))
     idx = jax.random.permutation(jax.random.PRNGKey(3), nb)[:kb]
     idx = idx.astype(jnp.int32)
-    p = block_compress(g, idx, bs, 2.0, interpret=True)
-    np.testing.assert_array_equal(np.asarray(p), np.asarray(
-        block_compress_ref(g, idx, bs, 2.0)))
-    np.testing.assert_array_equal(
-        np.asarray(block_decompress(p, idx, bs, d, interpret=True)),
-        np.asarray(block_decompress_ref(p, idx, bs, d)))
     np.testing.assert_allclose(
         np.asarray(momentum_scatter(g, p, idx, bs, 0.9, interpret=True)),
         np.asarray(momentum_scatter_ref(g, p, idx, bs, 0.9)), atol=1e-6)
@@ -171,14 +250,14 @@ def test_compress_unbiased_with_decompress():
     selected blocks, zeros elsewhere)."""
     d, bs = 1024, 128
     nb = d // bs
-    g = jax.random.normal(KEY, (d,))
+    g = jax.random.normal(KEY, (2, d))
     idx = jnp.array([0, 3], jnp.int32)
     alpha = nb / 2
     est = block_decompress(block_compress(g, idx, bs, alpha, interpret=True),
                            idx, bs, d, interpret=True)
-    dense = np.zeros(d, np.float32)
-    dense[:bs] = np.asarray(g[:bs]) * alpha
-    dense[3 * bs:4 * bs] = np.asarray(g[3 * bs:4 * bs]) * alpha
+    dense = np.zeros((2, d), np.float32)
+    dense[:, :bs] = np.asarray(g[:, :bs]) * alpha
+    dense[:, 3 * bs:4 * bs] = np.asarray(g[:, 3 * bs:4 * bs]) * alpha
     np.testing.assert_allclose(np.asarray(est), dense, rtol=1e-5)
 
 

@@ -9,6 +9,7 @@ tests and only the worker that runs this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -72,26 +73,52 @@ def test_aggregation_kernels_compile(compile_for, rule, b):
     assert "tpu_custom_call" in hlo
 
 
-# d = 2^27 holds 262,144 blocks: the id / slot vectors then exceed what one
-# call may prefetch into SMEM, so the kernels run as a chain of segments
+# d = 2^27 holds 262,144 blocks: the momentum scatter's slot vector then
+# exceeds what one call may prefetch into SMEM, so it runs as a chain of
+# segments; compress and decompress are one call at any d
 @pytest.mark.parametrize("d", [D, 1 << 27])
 @pytest.mark.parametrize("op", ["compress", "decompress", "scatter"])
 def test_randk_kernels_compile(compile_for, op, d):
     from repro.kernels.randk import randk as K
     kb = round(RATIO * (d // BLOCK))
     ids = ((kb,), jnp.int32)
-    dense, payload = ((d,), jnp.float32), ((kb * BLOCK,), jnp.float32)
     if op == "compress":
         hlo = compile_for(lambda g, i: K.block_compress(g, i, BLOCK, 20.0),
-                          dense, ids)
+                          ((4, d), jnp.float32), ids)
     elif op == "decompress":
         hlo = compile_for(lambda p, i: K.block_decompress(p, i, BLOCK, d),
-                          payload, ids)
+                          ((4, kb * BLOCK), jnp.float32), ids)
     else:
         hlo = compile_for(
             lambda m, p, i: K.momentum_scatter(m, p, i, BLOCK, 0.9),
-            dense, payload, ids)
+            ((d,), jnp.float32), ((kb * BLOCK,), jnp.float32), ids)
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("mask", ["global", "local"])
+@pytest.mark.parametrize("op", ["compress", "decompress"])
+def test_randk_bank_kernels_compile_at_stablelm_bank(compile_for, op, mask):
+    """The cell's bank: 4 workers' [416,179,200] bf16 rows, Block-RandK
+    0.05 / 512 (k = 40,642) under one shared id vector or one per row. The
+    kernel reads and writes the [4, D] arrays as they are: the compiled
+    program holds the one custom call, and no copy, pad or slice of them."""
+    from repro.kernels.randk import randk as K
+    n, d = 4, 416_179_200
+    kb = round(RATIO * (d // BLOCK))
+    assert kb == 40_642
+    ids = ((kb,) if mask == "global" else (n, kb), jnp.int32)
+    if op == "compress":
+        hlo = compile_for(lambda g, i: K.block_compress(g, i, BLOCK, 20.0),
+                          ((n, d), jnp.bfloat16), ids)
+    else:
+        hlo = compile_for(lambda p, i: K.block_decompress(p, i, BLOCK, d),
+                          ((n, kb * BLOCK), jnp.bfloat16), ids)
+    assert hlo.count("tpu_custom_call") == 1
+    made = re.findall(r"= (bf16\[[\d,]+\])\S* ([\w-]+)\(", hlo)
+    big = {f"bf16[{n},{d}]", f"bf16[{n},{kb * BLOCK}]"}
+    opcodes = {opcode for shape, opcode in made if shape in big}
+    assert "custom-call" in opcodes, opcodes
+    assert opcodes <= {"parameter", "bitcast", "custom-call"}, opcodes
 
 
 def test_flash_attention_compiles_at_stablelm_heads(compile_for):
